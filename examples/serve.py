@@ -162,7 +162,9 @@ def main() -> int:
     print("\n" + report.render(tree))
     total = args.batch * args.rounds * args.tokens
     tput = total / max(decode_wall, 1e-9)
-    print(f"\ndecode throughput: {tput:.1f} tok/s (CPU, interpret-free jnp path)")
+    dev = jax.devices()[0]
+    print(f"\ndecode throughput: {tput:.1f} tok/s ({dev.platform}, "
+          f"{dev.device_kind}, jnp path)")
     return 0
 
 

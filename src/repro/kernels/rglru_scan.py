@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+CHUNK = 8       # time steps per aligned load (one f32 sublane tile)
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry, *, block_t: int):
@@ -31,17 +31,20 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry, *, block_t: int):
     def _init():
         carry[...] = h0_ref[...]
 
-    a = a_ref[...]            # (bb, block_t, bw)
-    b = b_ref[...]
-    h = carry[...]            # (bb, bw)
+    def chunk(c, h):                      # h: (bb, bw)
+        # Mosaic indexes the time (sublane) axis only at aligned offsets:
+        # load CHUNK steps at once and walk them with static indices
+        rows = pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
+        a = a_ref[:, rows, :]             # (bb, CHUNK, bw)
+        b = b_ref[:, rows, :]
+        hs = []
+        for t in range(CHUNK):
+            h = a[:, t, :] * h + b[:, t, :]
+            hs.append(h)
+        o_ref[:, rows, :] = jnp.stack(hs, axis=1).astype(o_ref.dtype)
+        return h
 
-    def step(t, h):
-        h_new = a[:, t, :] * h + b[:, t, :]
-        o_ref[:, t, :] = h_new.astype(o_ref.dtype)
-        return h_new
-
-    h = jax.lax.fori_loop(0, block_t, step, h)
-    carry[...] = h
+    carry[...] = jax.lax.fori_loop(0, block_t // CHUNK, chunk, carry[...])
 
 
 def rglru_scan_kernel(a: jax.Array, b: jax.Array,
@@ -57,9 +60,10 @@ def rglru_scan_kernel(a: jax.Array, b: jax.Array,
     block_b = min(block_b, B)
     block_t = min(block_t, S)
     block_w = min(block_w, W)
-    if B % block_b or S % block_t or W % block_w:
+    if B % block_b or S % block_t or W % block_w or block_t % CHUNK:
         raise ValueError(f"dims {(B, S, W)} must divide blocks "
-                         f"{(block_b, block_t, block_w)}")
+                         f"{(block_b, block_t, block_w)}, and block_t be a "
+                         f"multiple of {CHUNK}")
     grid = (B // block_b, W // block_w, S // block_t)
 
     kernel = functools.partial(_rglru_kernel, block_t=block_t)
@@ -77,7 +81,7 @@ def rglru_scan_kernel(a: jax.Array, b: jax.Array,
                                lambda bi, wi, ti: (bi, ti, wi)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_w), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

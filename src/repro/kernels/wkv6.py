@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+CHUNK = 8       # time steps per aligned load (one f32 sublane tile)
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, s_scr,
@@ -32,19 +32,27 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, s_scr,
     def _init():
         s_scr[...] = s0_ref[0]
 
-    u = u_ref[0].astype(jnp.float32)                  # (1, dh) -> (dh,)
+    u = u_ref[0].astype(jnp.float32)                  # (dh, 1)
 
-    def step(t, s):
-        r = r_ref[0, t, :].astype(jnp.float32)        # (dh,)
-        k = k_ref[0, t, :].astype(jnp.float32)
-        v = v_ref[0, t, :].astype(jnp.float32)
-        w = jnp.exp(lw_ref[0, t, :].astype(jnp.float32))
-        kv = k[:, None] * v[None, :]                  # (dh, dh) rank-1
-        y = jnp.sum((s + u[0][:, None] * kv) * r[:, None], axis=0)
-        y_ref[0, t, :] = y.astype(y_ref.dtype)
-        return w[:, None] * s + kv
+    def chunk(c, s):
+        # Mosaic loads rows only at sublane-aligned offsets: take CHUNK rows
+        # at once and walk them with static indices; the column views of r,
+        # k and w come from one transpose per chunk
+        rows = pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
+        r = r_ref[0, rows, :].astype(jnp.float32).T   # (dh, CHUNK)
+        k = k_ref[0, rows, :].astype(jnp.float32).T
+        w = jnp.exp(lw_ref[0, rows, :].astype(jnp.float32)).T
+        v = v_ref[0, rows, :].astype(jnp.float32)     # (CHUNK, dh)
+        ys = []
+        for t in range(CHUNK):
+            kv = k[:, t:t + 1] * v[t:t + 1, :]        # (dh, dh) rank-1
+            ys.append(jnp.sum((s + u * kv) * r[:, t:t + 1], axis=0,
+                              keepdims=True))
+            s = w[:, t:t + 1] * s + kv
+        y_ref[0, rows, :] = jnp.concatenate(ys, axis=0).astype(y_ref.dtype)
+        return s
 
-    s_scr[...] = jax.lax.fori_loop(0, block_t, step, s_scr[...])
+    s_scr[...] = jax.lax.fori_loop(0, block_t // CHUNK, chunk, s_scr[...])
 
 
 def wkv6_kernel(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
@@ -56,8 +64,9 @@ def wkv6_kernel(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
     if s0 is None:
         s0 = jnp.zeros((BH, dh, dh), jnp.float32)
     block_t = min(block_t, T)
-    if T % block_t:
-        raise ValueError(f"T={T} must divide block_t={block_t}")
+    if T % block_t or block_t % CHUNK:
+        raise ValueError(f"T={T} must divide block_t={block_t}, itself a "
+                         f"multiple of {CHUNK}")
     grid = (BH, 1, T // block_t)
 
     kernel = functools.partial(_wkv6_kernel, block_t=block_t)
@@ -69,13 +78,13 @@ def wkv6_kernel(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
             pl.BlockSpec((1, block_t, dh), lambda b, _, ti: (b, ti, 0)),
             pl.BlockSpec((1, block_t, dh), lambda b, _, ti: (b, ti, 0)),
             pl.BlockSpec((1, block_t, dh), lambda b, _, ti: (b, ti, 0)),
-            pl.BlockSpec((1, 1, dh), lambda b, _, ti: (b, 0, 0)),
+            pl.BlockSpec((1, dh, 1), lambda b, _, ti: (b, 0, 0)),
             pl.BlockSpec((1, dh, dh), lambda b, _, ti: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_t, dh), lambda b, _, ti: (b, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, dh), r.dtype),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, logw, u.reshape(BH, 1, dh), s0)
+    )(r, k, v, logw, u.reshape(BH, dh, 1), s0)

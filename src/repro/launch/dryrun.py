@@ -1,7 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (jax locks the device
-# count at first init).  Everything below may import jax freely.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=512")
+# The lines above MUST run before any jax import (jax locks the platform and
+# the device count at first init).  The dry run compiles on 512 host devices
+# and never takes a chip; the per-cell children inherit both settings.
+# Everything below may import jax freely.
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and record memory/cost/collective analysis.

@@ -6,7 +6,8 @@ against a probe in tests/test_hlo_analysis.py).  This module parses the
 compiled per-device HLO text and aggregates, multiplying while bodies by
 their trip counts:
 
-  flops            dots (2*M*N*K), convolutions approximated, elementwise 1/el
+  flops            dots (2*M*N*K), convolutions (valid window taps; the TPU
+                   compiler emits most dots as convolutions), elementwise 1/el
   hbm bytes        operands+results of top-level (fusion-boundary) ops
   collective bytes per kind (all-reduce / all-gather / reduce-scatter /
                    all-to-all / collective-permute), result-shape proxy
@@ -19,6 +20,8 @@ from __future__ import annotations
 import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
+
+import numpy as np
 
 _DT_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3": 1,
              "f8e5m2": 1, "f8e4m3fn": 1, "s64": 8, "u64": 8, "s32": 4,
@@ -110,6 +113,9 @@ class Stats:
 
 
 _COMMENT_RE = re.compile(r"/\*.*?\*/")
+# a shape's layout, e.g. ``{2,1,0:T(8,128)(2,1)S(1)}`` in TPU HLO: its tile
+# parens would otherwise read as an opcode call
+_LAYOUT_RE = re.compile(r"\]\{[^{}]*\}")
 
 
 def parse_module(hlo: str) -> Dict[str, Computation]:
@@ -118,7 +124,7 @@ def parse_module(hlo: str) -> Dict[str, Computation]:
     for raw in hlo.splitlines():
         # strip /*index=N*/ comments: large tuple types embed them and the
         # '=' inside breaks op matching (that silently hid every big while)
-        line = _COMMENT_RE.sub("", raw).rstrip()
+        line = _LAYOUT_RE.sub("]", _COMMENT_RE.sub("", raw)).rstrip()
         stripped = line.strip()
         if cur is None:
             m = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\([^)]*\))?.*\{\s*$",
@@ -223,6 +229,51 @@ def _dot_flops(op: Op, comp: Computation) -> float:
     return 2.0 * result_numel * k
 
 
+def _dims(type_str: str) -> List[int]:
+    m = _SHAPE_RE.search(type_str)
+    return [int(d) for d in m.group(2).split(",") if d] if m else []
+
+
+def _window(line: str) -> Dict[str, List[List[int]]]:
+    """``window={size=5x4 stride=4x1 pad=0_0x3_3 lhs_dilate=5x1}`` ->
+    {attr: per-spatial-dim int lists} (pad entries are [lo, hi])."""
+    m = re.search(r"window=\{([^}]*)\}", line)
+    out: Dict[str, List[List[int]]] = {}
+    for item in (m.group(1).split() if m else ()):
+        key, _, val = item.partition("=")
+        out[key] = [[int(x) for x in d.split("_")] for d in val.split("x")]
+    return out
+
+
+def _conv_flops(op: Op, comp: Computation) -> float:
+    """2 x multiply-adds of a convolution, counting only the (output
+    position, window tap) pairs that land on a real input element (padding
+    and lhs-dilation holes excluded).  The TPU compiler rewrites most
+    dot_generals as convolutions, so this is where a TPU step's matmul
+    flops live."""
+    m = re.search(r"dim_labels=(\w+)_(\w+)->(\w+)", op.line)
+    ops = _operands(op.line)
+    if not m or len(ops) < 2:
+        return 2.0 * _type_numel(op.type_str)
+    lhs_l, rhs_l, out_l = m.groups()
+    lhs, rhs = _dims(comp.types.get(ops[0], "")), _dims(comp.types.get(ops[1], ""))
+    out = _dims(op.type_str)
+    if len(lhs) != len(lhs_l) or len(rhs) != len(rhs_l) or len(out) != len(out_l):
+        return 2.0 * _type_numel(op.type_str)
+    win = _window(op.line)
+    macs = float(np.prod(out)) * rhs[rhs_l.index("i")]
+    for j, (w,) in enumerate(win.get("size", [])):
+        n, mo = lhs[lhs_l.index(str(j))], out[out_l.index(str(j))]
+        s = win["stride"][j][0] if "stride" in win else 1
+        lo = win["pad"][j][0] if "pad" in win else 0
+        ld = win["lhs_dilate"][j][0] if "lhs_dilate" in win else 1
+        rd = win["rhs_dilate"][j][0] if "rhs_dilate" in win else 1
+        p = (np.arange(mo)[:, None] * s + np.arange(w)[None, :] * rd - lo)
+        valid = (p >= 0) & (p <= (n - 1) * ld) & (p % ld == 0)
+        macs *= valid.sum() / mo
+    return 2.0 * macs
+
+
 class Analyzer:
     def __init__(self, hlo: str):
         self.comps = parse_module(hlo)
@@ -305,14 +356,15 @@ class Analyzer:
                     st.bytes += self._io_bytes(op, comp)
                 continue
             if oc == "convolution":
-                # approximate: 2 * result numel * (operand0 channels) — rare
-                st.flops += 2.0 * _type_numel(op.type_str) * 8
+                st.flops += _conv_flops(op, comp)
                 if top:
                     st.bytes += self._io_bytes(op, comp)
                 continue
+            if oc in _SKIP_BYTES:        # no compute, no traffic
+                continue
             # elementwise & everything else: 1 flop/elem
             st.flops += _type_numel(op.type_str)
-            if top and oc not in _SKIP_BYTES:
+            if top:
                 st.bytes += self._io_bytes(op, comp)
         self._memo[key] = st
         return st

@@ -24,11 +24,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
 
 
-def make_host_mesh(model_parallel: int = 1):
-    """Mesh over whatever devices exist (tests / CPU training driver)."""
+def make_host_mesh(model_parallel: int = 1, n_devices: int = 0):
+    """Mesh over the first ``n_devices`` devices (all when 0) of this host."""
     import numpy as np
     import jax
-    devs = jax.devices()
+    devs = jax.devices()[:n_devices or None]
     dp = len(devs) // model_parallel
     from jax.sharding import Mesh
     return Mesh(np.asarray(devs[:dp * model_parallel]).reshape(dp, model_parallel),

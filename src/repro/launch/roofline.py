@@ -22,10 +22,7 @@ import pathlib
 import sys
 from typing import Dict, List, Optional
 
-# TPU v5e constants (per chip)
-PEAK_FLOPS = 197e12       # bf16
-HBM_BW = 819e9            # bytes/s
-LINK_BW = 50e9            # bytes/s/link ICI
+from repro.perfdbg.attributes import HBM_BW, LINK_BW, PEAK_FLOPS
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / "benchmarks" / "results"
 

@@ -4,6 +4,11 @@ policy-actuated (launch layer: everything below is mechanism, this is use).
     PYTHONPATH=src python -m repro.launch.train --arch yi-34b --reduced \
         --steps 30 --batch 4 --seq 128 --ckpt-dir /tmp/ckpt --analyze-every 10
 
+    # a published config at full width, depth cut to fit one 16 GB TPU v5e
+    PYTHONPATH=src python -m repro.launch.train --arch rwkv6-3b --no-reduced \
+        --layers 4 --batch 4 --seq 1024 --steps 8 --analyze-every 4 \
+        --schema tpu
+
 Features exercised end-to-end (CPU-sized here, mesh-parametric for pods):
   * region-instrumented step (data / step / checkpoint) feeding an
     AnalysisSession: every --analyze-every steps the recorder's live window
@@ -49,19 +54,52 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import os
+import statistics
 import sys
 import time
+from typing import Dict, List, Optional
 
 import numpy as np
 
 
+@dataclasses.dataclass
+class TrainRun:
+    """What one run of the driver measured, for in-process callers."""
+    cfg: object                     # the ModelConfig that ran
+    losses: List[float]
+    step_s: List[float]             # host wall of each step, synced on loss
+    compile_s: float                # lowering + compiling the train step
+    windows_analyzed: int           # analysis windows that produced a report
+    step_attrs: Dict[str, float]    # recorded step-region attrs, last window
+    coverage: str                   # HLO cost-provider coverage ('' if none)
+    bytes_in_use: List[Optional[int]]   # per mesh device, state still live
+
+    @property
+    def median_step_ms(self) -> float:
+        return 1e3 * statistics.median(self.step_s)
+
+
 def main(argv=None) -> int:
+    run(argv)
+    return 0
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-34b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="CPU-sized config of the family (--d-model sets its "
+                         "width); --no-reduced runs the published config")
     ap.add_argument("--d-model", type=int, default=256)
-    ap.add_argument("--layers", type=int, default=0)
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth; cuts a published config in whole "
+                         "block_pattern periods")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="data-parallel (FSDP) mesh over the first N devices "
+                         "(default: all)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
@@ -165,6 +203,12 @@ def main(argv=None) -> int:
                     help="hosts to shard each window across under "
                          "--chaos-seed (must be <= the pod rank count)")
     ap.add_argument("--resume", action="store_true")
+    return ap
+
+
+def run(argv=None) -> TrainRun:
+    """Train, analyze every window, and return what was measured."""
+    ap = build_parser()
     args = ap.parse_args(argv)
     if args.data_hosts > 1 and args.sim_ranks > 1:
         ap.error("--data-hosts and --sim-ranks are mutually exclusive: the "
@@ -175,8 +219,6 @@ def main(argv=None) -> int:
                  f"{args.data_hosts} (every host gets at least one row)")
 
     import jax
-    import jax.numpy as jnp
-    from repro.configs import reduced_config, get_config
     from repro.core import (AnalysisSession, AsyncAnalysisSession,
                             PolicyEngine, RegionTree, make_policies)
     from repro.core.roughset import ROLE_IO
@@ -192,27 +234,32 @@ def main(argv=None) -> int:
     from repro.optim import adamw
     from repro.perfdbg import AnalyticCosts, Instrumenter, RegionRecorder
     from repro.perfdbg.instrument import CPU_CLOCK, NOMINAL_HZ
+    from repro.perfdbg.attributes import device_peaks
     from repro.perfdbg.schema import SUM
     from repro.ckpt import checkpoint as ckpt
 
-    overrides = dict(d_model=args.d_model,
-                     n_heads=max(args.d_model // 64, 1),
-                     n_kv_heads=max(args.d_model // 128, 1),
-                     d_ff=args.d_model * 3, vocab_size=2048)
-    if args.layers:
-        overrides["n_layers"] = args.layers
-    cfg = reduced_config(args.arch, **overrides) if args.reduced \
-        else get_config(args.arch)
+    cfg = model_config(args)
     print(f"[train] {cfg.name}: ~{cfg.total_params()/1e6:.1f}M params, "
           f"{cfg.n_layers}L d={cfg.d_model}", flush=True)
+    if args.schema == "tpu" and jax.devices()[0].platform == "tpu":
+        # the schema's roofline ratios need this chip's published peaks
+        device_peaks(jax.devices()[0].device_kind)
 
-    mesh = make_host_mesh()
+    steps_lib.use_compile_cache()
+    mesh = make_host_mesh(n_devices=args.devices)
     opt_cfg = adamw.AdamWConfig(lr=args.lr, warmup_steps=5,
                                 decay_steps=max(args.steps, 10))
     bshapes = input_specs(cfg, args.batch, args.seq, "train")
+    t0 = time.perf_counter()
     with mesh:
         jitted, (st_shapes, st_sh, b_sh) = steps_lib.jit_train_step(
             cfg, opt_cfg, mesh, bshapes, microbatches=1)
+        # one compile serves both the step loop and the HLO cost provider
+        compiled = jitted.lower(st_shapes, bshapes).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"[train] compiled train step on {mesh.devices.size} "
+          f"{mesh.devices.flat[0].device_kind} device(s) in "
+          f"{compile_s:.1f}s", flush=True)
 
     H = max(args.data_hosts, 1)
     data = SyntheticTokens(cfg.vocab_size, args.batch, args.seq)
@@ -220,7 +267,7 @@ def main(argv=None) -> int:
         w = np.ones(H)
         w[0] = args.data_skew
         data.set_partition(Partition(w))
-    state = steps_lib.init_state(cfg, opt_cfg, seed=0)
+    state = steps_lib.init_state(cfg, opt_cfg, seed=0, shardings=st_sh)
     start_step = 0
 
     saver = None
@@ -233,7 +280,7 @@ def main(argv=None) -> int:
             # actuated partition therefore survives the restart and
             # overrides the flag-built one above
             restored, manifest = ckpt.restore(args.ckpt_dir, {"state": state})
-            state = restored["state"]
+            state = jax.device_put(restored["state"], st_sh)
             data.load_state_dict(manifest["data"])
             start_step = int(manifest["step"])
             print(f"[train] restored step {start_step} from {args.ckpt_dir}",
@@ -260,12 +307,12 @@ def main(argv=None) -> int:
         d_model=cfg.d_model, n_layers=cfg.n_layers,
         tokens_per_step=tokens_per_step,
         checkpoint_io_bytes=0.0 if not args.ckpt_dir else 1.0)
+    coverage = ""
     if costs_mode == "hlo":
-        with mesh:
-            hlo_text = steps_lib.compiled_hlo(jitted, st_shapes, bshapes)
         provider = steps_lib.hlo_cost_provider(
-            hlo_text, region_names, anchor="step", base=provider)
-        print("[costs] coverage: " + provider.render_coverage(), flush=True)
+            compiled.as_text(), region_names, anchor="step", base=provider)
+        coverage = provider.render_coverage()
+        print("[costs] coverage: " + coverage, flush=True)
     step_costs = provider.region_costs("step")
     flops_per_step = step_costs.get("hlo_flops", 0.0)
     print(f"[costs] {costs_mode} step: "
@@ -638,6 +685,7 @@ def main(argv=None) -> int:
 
     data.start_prefetch()
     losses = []
+    step_s = []
     win_start = start_step
     with mesh:
         for step in range(start_step, args.steps):
@@ -662,10 +710,12 @@ def main(argv=None) -> int:
                             sum(int(v.nbytes) for v in hb.values())
                             for hb in host_batches]
                         step_shares[:] = step_bytes / step_bytes.sum()
-                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                    batch = jax.device_put(batch, b_sh)
                 with region("step", instructions=flops_per_step):
-                    state, metrics = jitted(state, batch)
+                    t0 = time.perf_counter()
+                    state, metrics = compiled(state, batch)
                     loss = float(metrics["loss"])
+                    step_s.append(time.perf_counter() - t0)
                 with region("checkpoint", nominal_cpi=1.0):
                     if saver and (step + 1) % args.ckpt_every == 0:
                         saver.save(step + 1, {"state": state},
@@ -709,6 +759,7 @@ def main(argv=None) -> int:
               f"{journal.path}", flush=True)
     print(report.render(tree), flush=True)
     wins = rec.windows()
+    vals = {}
     if wins:
         # recorded (not provider-advertised) attribute totals of the step
         # region, last window — the end-to-end check that schema fields
@@ -746,7 +797,39 @@ def main(argv=None) -> int:
     ok = len(losses) >= 2 and losses[-1] < losses[0] and np.isfinite(losses[-1])
     print(f"[train] loss {losses[0]:.4f} -> {losses[-1]:.4f} "
           f"({'improved' if ok else 'check convergence'})", flush=True)
-    return 0
+    # the backend reports no memory stats on the CPU
+    in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+              for d in mesh.devices.flat]
+    if any(b is not None for b in in_use):
+        print("[train] bytes in use per device: " + " ".join(
+            f"{d.id}:{b / 1e9:.2f}GB" for d, b in zip(mesh.devices.flat,
+                                                      in_use)), flush=True)
+    return TrainRun(cfg=cfg, losses=losses, step_s=step_s,
+                    compile_s=compile_s,
+                    windows_analyzed=len(report.windows)
+                    - report.failed_count(),
+                    step_attrs=vals, coverage=coverage, bytes_in_use=in_use)
+
+
+def model_config(args):
+    """The config a run trains: the family's CPU-sized reduction (default),
+    or the published config at full width with only its depth cut."""
+    from repro.configs import get_config, reduced_config
+    if args.reduced:
+        overrides = dict(d_model=args.d_model,
+                         n_heads=max(args.d_model // 64, 1),
+                         n_kv_heads=max(args.d_model // 128, 1),
+                         d_ff=args.d_model * 3, vocab_size=2048)
+        if args.layers:
+            overrides["n_layers"] = args.layers
+        return reduced_config(args.arch, **overrides)
+    cfg = get_config(args.arch)
+    if not args.layers or args.layers >= cfg.n_layers:
+        return cfg
+    unit = len(cfg.block_pattern)
+    n = max(args.layers // unit, 1) * unit
+    print(f"[train] reduced: n_layers {cfg.n_layers}→{n}", flush=True)
+    return dataclasses.replace(cfg, n_layers=n)
 
 
 if __name__ == "__main__":

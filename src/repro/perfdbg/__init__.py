@@ -1,5 +1,6 @@
 """Instrumentation + collection substrate for AutoAnalyzer (paper §4)."""
-from .attributes import (dominant_term, region_attributes, roofline_terms,
+from .attributes import (DEVICE_PEAKS, DevicePeaks, device_peaks,
+                         dominant_term, region_attributes, roofline_terms,
                          HBM_BW, LINK_BW, PEAK_FLOPS)
 from .costs import (AnalyticCosts, CostProvider, HloCosts, ModuleCoverage,
                     PROVIDER_KEYS, boundedness_ratios)

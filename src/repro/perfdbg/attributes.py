@@ -16,14 +16,49 @@ Opterons), one whose 'network_io' flag is 1 is collective-bound, etc.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, Mapping
 
 import numpy as np
 
-# TPU v5e roofline constants (per chip)
-PEAK_FLOPS = 197e12       # bf16
-HBM_BW = 819e9            # bytes/s
-LINK_BW = 50e9            # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    flops: float          # bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    hbm_bytes: float      # HBM capacity
+    link_bw: float        # bytes/s per inter-chip link
+
+
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s,
+#: 1,600 Gbit/s of inter-chip interconnect over four links.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+                               link_bw=50e9),
+}
+
+#: The chip the ``tpu`` schema's ratios are written against off-chip (CPU
+#: rehearsals, stored windows).
+NOMINAL_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: str) -> DevicePeaks:
+    """Peaks of ``kind``; a chip that is not in the table is an error, never
+    silently another chip's numbers."""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r} "
+                       f"(known: {sorted(DEVICE_PEAKS)}); add its entry to "
+                       f"perfdbg.attributes.DEVICE_PEAKS") from None
+
+
+_NOMINAL = DEVICE_PEAKS[NOMINAL_KIND]
+PEAK_FLOPS = _NOMINAL.flops
+HBM_BW = _NOMINAL.hbm_bw
+LINK_BW = _NOMINAL.link_bw
 RIDGE_INTENSITY = PEAK_FLOPS / HBM_BW   # ~240 flops/byte
 
 

@@ -189,10 +189,9 @@ class TestWhileSlicedFixture:
 class TestRegionPrefixMatching:
     """Hand-written module pinning the attribution arithmetic exactly.
 
-    Standalone flops (the analyzer counts parameters/elementwise at 1
-    flop/element): attn.fwd = 64+64+1024 = 1152; ffn_fwd = 64+128+2048 =
-    2240; sum.helper = 3; main = 256 (params) + 1152 + 64 (add) + 2240 =
-    3712, plus a 256-byte all-reduce."""
+    Standalone flops (elementwise at 1 flop/element; parameters cost
+    none): attn.fwd = 1024 (dot); ffn_fwd = 2048 (dot); sum.helper = 1;
+    main = 1024 + 64 (add) + 2048 = 3136, plus a 256-byte all-reduce."""
 
     def make(self, regions=("outer", "attn", "ffn")):
         a = Analyzer(fixture("regions_handwritten.hlo.txt"))
@@ -201,18 +200,18 @@ class TestRegionPrefixMatching:
 
     def test_exact_attribution(self):
         prov = self.make()
-        assert prov.region_costs("attn")["hlo_flops"] == 1152.0
-        assert prov.region_costs("ffn")["hlo_flops"] == 2240.0
+        assert prov.region_costs("attn")["hlo_flops"] == 1024.0
+        assert prov.region_costs("ffn")["hlo_flops"] == 2048.0
         outer = prov.region_costs("outer")
-        assert outer["hlo_flops"] == 3712.0 - 1152.0 - 2240.0   # residual
+        assert outer["hlo_flops"] == 3136.0 - 1024.0 - 2048.0   # residual
         assert outer["collective_bytes"] == 256.0    # stays on the anchor
 
     def test_coverage_accounting(self):
         cov = self.make().coverage()["outer"]
-        assert cov.total_flops == 3712.0
-        assert cov.attributed_flops == 3392.0
-        assert cov.residual_flops == 320.0
-        assert cov.coverage == pytest.approx(3392.0 / 3712.0)
+        assert cov.total_flops == 3136.0
+        assert cov.attributed_flops == 3072.0
+        assert cov.residual_flops == 64.0
+        assert cov.coverage == pytest.approx(3072.0 / 3136.0)
         assert cov.matched == (("attn.fwd", "attn"), ("ffn_fwd", "ffn"))
         assert cov.unmatched == 1                    # sum.helper
         assert "outer" in cov.render()
@@ -229,7 +228,7 @@ class TestRegionPrefixMatching:
         """Without named regions the anchor carries the whole module —
         nothing is lost, it is just unattributed."""
         solo = self.make(regions=("outer",))
-        assert solo.region_costs("outer")["hlo_flops"] == 3712.0
+        assert solo.region_costs("outer")["hlo_flops"] == 3136.0
         assert solo.coverage()["outer"].coverage == 0.0
 
 

@@ -111,3 +111,55 @@ def test_nested_scan_multiplies():
     stats = analyze(compiled.as_text())
     expected = 2 * M ** 3 * 4 * 5
     assert stats["flops"] == pytest.approx(expected, rel=0.25)
+
+
+# TPU HLO as the TPU compiler prints it: tiled layouts whose parens look
+# like calls, and dot_generals rewritten as convolutions
+TPU_HLO = """HloModule jit_f, is_scheduled=true
+
+%fused_computation (param_0: bf16[4,512,256], param_1: bf16[256,1024,1]) -> bf16[4,512,1024] {
+  %param_0 = bf16[4,512,256]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_1 = bf16[256,1024,1]{1,0,2:T(8,128)(2,1)} parameter(1)
+  ROOT %convolution.1 = bf16[4,512,1024]{2,1,0:T(8,128)(2,1)} convolution(%param_0, %param_1), window={size=1}, dim_labels=0bf_io0->0bf
+}
+
+ENTRY %main.9 (a: bf16[4,512,256], b: bf16[256,1024,1]) -> bf16[4,512,1024] {
+  %a = bf16[4,512,256]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %b = bf16[256,1024,1]{1,0,2:T(8,128)(2,1)} parameter(1)
+  %step = s32[]{:T(128)} constant(0)
+  ROOT %fusion.1 = bf16[4,512,1024]{2,1,0:T(8,128)(2,1)} fusion(%a, %b), kind=kOutput, calls=%fused_computation
+}
+"""
+
+
+def test_tpu_layouts_and_dot_as_convolution():
+    a = Analyzer(TPU_HLO)
+    assert a.entry == "main.9"
+    assert [op.opcode for op in a.comps["main.9"].ops] == [
+        "parameter", "parameter", "constant", "fusion"]
+    st = a.stats()
+    assert st.flops == 2 * 4 * 512 * 1024 * 256
+    assert st.bytes == 2 * (4 * 512 * 256 + 256 * 1024 + 4 * 512 * 1024)
+
+
+@pytest.mark.parametrize("pad,lhs_dil,stride", [
+    ((0, 0), 1, 1), ((2, 1), 1, 1), ((1, 1), 2, 1), ((3, 0), 3, 2)])
+def test_convolution_counts_only_valid_taps(pad, lhs_dil, stride):
+    """Padding and lhs-dilation holes cost no multiply-adds: the count is
+    the (output position, tap) pairs that land on a real input element."""
+    B, N, C, O, W = 2, 9, 8, 16, 4
+    def f(x, k):
+        return jax.lax.conv_general_dilated(
+            x, k, window_strides=(stride,), padding=(pad,),
+            lhs_dilation=(lhs_dil,), dimension_numbers=("NWC", "WIO", "NWC"))
+    compiled = jax.jit(f).lower(
+        jax.ShapeDtypeStruct((B, N, C), jnp.float32),
+        jax.ShapeDtypeStruct((W, C, O), jnp.float32)).compile()
+    # brute force over the dilated, padded input
+    n_dil = (N - 1) * lhs_dil + 1
+    m = (n_dil + sum(pad) - W) // stride + 1
+    pairs = sum(1 for o in range(m) for t in range(W)
+                if 0 <= o * stride + t - pad[0] < n_dil
+                and (o * stride + t - pad[0]) % lhs_dil == 0)
+    assert analyze(compiled.as_text())["flops"] == pytest.approx(
+        2 * B * O * C * pairs, rel=0.05)

@@ -211,6 +211,17 @@ class TestStraggler:
 
 
 class TestAttributes:
+    def test_peaks_keyed_by_device_kind(self):
+        from repro.perfdbg.attributes import (DEVICE_PEAKS, HBM_BW,
+                                              PEAK_FLOPS, device_peaks)
+        v5e = device_peaks("TPU v5 lite")
+        assert (v5e.flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                         16e9)
+        assert (PEAK_FLOPS, HBM_BW) == (v5e.flops, v5e.hbm_bw)
+        with pytest.raises(KeyError, match="TPU v4"):
+            device_peaks("TPU v4")          # never another chip's numbers
+        assert "TPU v4" not in DEVICE_PEAKS
+
     def test_roofline_terms_and_dominant(self):
         terms = roofline_terms(flops=197e12, bytes_hbm=819e9 * 2,
                                collective_bytes=0)

@@ -126,3 +126,17 @@ def test_run_chaos_process_executor_accounting():
     assert proc_res.faults == thread_res.faults
     assert proc_res.failed == thread_res.failed
     assert proc_res.report_text == thread_res.report_text
+
+
+def test_process_workers_never_import_jax():
+    """The spawn children run the jax-free core layer only, so none can
+    initialize a backend and contend for the chip its parent holds."""
+    tree = small_tree()
+    pipe = AsyncAnalysisSession(tree, workers=2, executor=PROCESS)
+    for s in straggler_stream(tree, 3):
+        pipe.submit(s)
+    probe = "'jax' in __import__('sys').modules"
+    loaded = [pipe._proc_pool.submit(eval, probe).result(timeout=120)
+              for _ in range(4)]
+    pipe.close(timeout=120.0)
+    assert loaded == [False] * 4

@@ -1,7 +1,9 @@
 """Integration tests: sharded train/serve steps on the host mesh, the
 training driver loop, and mixed-precision optimizer state."""
+import dataclasses
 import json
 import os
+import pathlib
 import re
 import subprocess
 import sys
@@ -269,3 +271,99 @@ class TestDriver:
         assert rc == 0
         from repro.ckpt import checkpoint as ckpt
         assert ckpt.latest_step(tmp_path) == 5
+
+
+class TestPublishedWidth:
+    """``--no-reduced`` reaches the published config; ``--layers`` cuts only
+    its depth, in whole block_pattern periods."""
+
+    def _cfg(self, argv):
+        from repro.launch.train import build_parser, model_config
+        return model_config(build_parser().parse_args(argv))
+
+    def test_reduced_stays_the_default(self):
+        cfg = self._cfg(["--arch", "rwkv6-3b", "--d-model", "128"])
+        assert cfg.d_model == 128 and cfg.vocab_size == 2048
+
+    @pytest.mark.parametrize("arch,layers,want", [
+        ("rwkv6-3b", 4, 4),               # one-block period
+        ("recurrentgemma-9b", 5, 3),      # (rec, rec, attn): whole periods
+        ("recurrentgemma-9b", 1, 3),      # never below one period
+        ("yi-34b", 0, 60),                # no cut
+    ])
+    def test_no_reduced_cuts_depth_only(self, arch, layers, want, capsys):
+        from repro.configs import get_config
+        full = get_config(arch)
+        cfg = self._cfg(["--arch", arch, "--no-reduced", "--d-model", "128",
+                         "--layers", str(layers)])
+        assert cfg.n_layers == want
+        assert cfg == dataclasses.replace(full, n_layers=want)
+        if want != full.n_layers:
+            assert (f"reduced: n_layers {full.n_layers}→{want}"
+                    in capsys.readouterr().out)
+
+    def test_run_returns_what_it_measured(self):
+        from repro.launch.train import run
+        res = run(["--arch", "rwkv6-3b", "--steps", "4", "--batch", "2",
+                   "--seq", "32", "--d-model", "128", "--analyze-every", "2",
+                   "--schema", "tpu", "--costs", "hlo"])
+        assert len(res.losses) == 4 and np.all(np.isfinite(res.losses))
+        assert res.windows_analyzed == 2
+        assert res.step_attrs["hlo_flops"] > 0
+        assert res.coverage.startswith("step:")
+        assert res.compile_s > 0 and res.median_step_ms > 0
+        assert len(res.bytes_in_use) == len(jax.devices())
+
+    def test_sharded_init_matches_eager_init(self):
+        cfg = reduced_config("rwkv6-3b")
+        opt_cfg = adamw.AdamWConfig()
+        mesh = make_host_mesh()
+        with mesh:
+            _, (_, st_sh, _) = steps_lib.jit_train_step(
+                cfg, opt_cfg, mesh, input_specs(cfg, 2, 32, "train"))
+        eager = steps_lib.init_state(cfg, opt_cfg, seed=3)
+        placed = steps_lib.init_state(cfg, opt_cfg, seed=3, shardings=st_sh)
+        for a, b, sh in zip(jax.tree_util.tree_leaves(eager),
+                            jax.tree_util.tree_leaves(placed),
+                            jax.tree_util.tree_leaves(st_sh)):
+            # the same draws; jit may fuse the init scaling one ulp apart
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-7)
+            assert b.sharding == sh
+
+
+class TestCompileCache:
+    """``steps.use_compile_cache``: the environment's directory wins; else
+    the checkout's fixed ``.jax_cache``.  Subprocesses: the cache directory
+    is read once per process."""
+
+    SNIPPET = ("import jax, jax.numpy as jnp\n"
+               "from repro.launch import steps\n"
+               "print(steps.use_compile_cache())\n"
+               "print(jax.config.jax_compilation_cache_dir)\n")
+
+    def _run(self, env, code):
+        out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                             text=True, env=env, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.split()
+
+    def test_environment_directory_holds_the_entries(self, tmp_path):
+        env = dict(os.environ, PYTHONPATH="src",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                   JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+        said = self._run(env, self.SNIPPET
+                         + "jax.jit(lambda x: x * 2)(jnp.ones(3))\n")
+        assert said == [str(tmp_path), str(tmp_path)]
+        assert any(tmp_path.iterdir())
+
+    def test_default_is_the_checkout_cache(self):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env["PYTHONPATH"] = "src"
+        said = self._run(env, self.SNIPPET)
+        assert said == [str(steps_lib.CACHE_DIR)] * 2
+        assert steps_lib.CACHE_DIR.name == ".jax_cache"
+        assert steps_lib.CACHE_DIR.parent == \
+            pathlib.Path(__file__).resolve().parents[1]
