@@ -84,6 +84,7 @@ from typing import Callable, Dict, List, Optional
 
 from .regions import RegionTree
 from .session import AnalysisSession, SessionReport, WindowEntry
+from .spans import span
 
 BLOCK = "block"
 DROP_OLDEST = "drop_oldest"
@@ -295,18 +296,19 @@ class AsyncAnalysisSession:
                     self._cv.wait()
                 if not self._q:          # closed and fully drained
                     return
-                _, snap, label = self._q.popleft()
+                seq, snap, label = self._q.popleft()
                 self._cv.notify_all()    # a blocked producer may proceed
             err = None
             ingested = False
             fired = []
             try:
-                entry = self._session.ingest_snapshot(snap, label=label)
-                ingested = True
-                if self._engine is not None:
-                    fired = self._engine.observe(entry, self._session)
-                if self._on_window is not None:
-                    self._on_window(entry)
+                with span("analysis.window", submission=seq):
+                    entry = self._session.ingest_snapshot(snap, label=label)
+                    ingested = True
+                    if self._engine is not None:
+                        fired = self._engine.observe(entry, self._session)
+                    if self._on_window is not None:
+                        self._on_window(entry)
             except BaseException as e:   # propagate to the producer side
                 err = e
             contained = (err is not None and not ingested and self._supervised)
@@ -384,21 +386,11 @@ class AsyncAnalysisSession:
             if claimed is None:
                 continue
             seq, snap, label = claimed
+            # the window's assembly (diagnosis, policies, on_window) runs
+            # later on whichever thread assembles, under analysis.assemble
             try:
-                if self._proc_pool is not None:
-                    # fault-injection hooks (chaos sessions) must fire in the
-                    # parent, deterministically per window, so tombstones land
-                    # in the same timeline slots for every executor kind
-                    check = getattr(self._session, "check_analyzer_fault",
-                                    None)
-                    if check is not None:
-                        check(snap)
-                    outcome: object = self._proc_pool.submit(
-                        _process_prepare, snap.to_bytes(),
-                        label or getattr(snap, "label", None)).result()
-                else:
-                    outcome = self._session.prepare_snapshot(
-                        snap, label=label, memo=memo)
+                with span("analysis.window", submission=seq):
+                    outcome = self._prepare(snap, label, memo)
             except BaseException as e:
                 outcome = _PrepareFailure(
                     e, label=label or getattr(snap, "label", None))
@@ -406,6 +398,21 @@ class AsyncAnalysisSession:
                 self._results[seq] = outcome
                 self._inflight -= 1
                 self._cv.notify_all()
+
+    def _prepare(self, snap, label, memo):
+        """The thread-safe analysis stage of one claimed window, in this
+        thread or in a process-pool replica."""
+        if self._proc_pool is not None:
+            # fault-injection hooks (chaos sessions) must fire in the
+            # parent, deterministically per window, so tombstones land in the
+            # same timeline slots for every executor kind
+            check = getattr(self._session, "check_analyzer_fault", None)
+            if check is not None:
+                check(snap)
+            return self._proc_pool.submit(
+                _process_prepare, snap.to_bytes(),
+                label or getattr(snap, "label", None)).result()
+        return self._session.prepare_snapshot(snap, label=label, memo=memo)
 
     def _can_assemble(self) -> bool:
         return not self._assembling and self._next_assemble in self._results
@@ -422,12 +429,14 @@ class AsyncAnalysisSession:
             try:
                 while True:
                     with self._cv:
-                        item = self._results.pop(self._next_assemble, None)
+                        seq = self._next_assemble
+                        item = self._results.pop(seq, None)
                         if item is None:
                             break
                         self._next_assemble += 1
                     if item is not _DROPPED:   # drops were counted at eviction
-                        self._assemble_one(item)
+                        with span("analysis.assemble", submission=seq):
+                            self._assemble_one(item)
             finally:
                 with self._cv:
                     self._assembling = False
@@ -482,11 +491,13 @@ class AsyncAnalysisSession:
             raise RuntimeError("analysis worker failed") from self._error
 
     # -- producer side -------------------------------------------------------
-    def submit(self, snap, label: Optional[str] = None) -> None:
+    def submit(self, snap, label: Optional[str] = None) -> int:
         """Enqueue one frozen window (a ``WindowSnapshot``); the only cost
         on the caller is the queue append (or a wait under ``block``) —
         plus, with a ``journal`` attached, one local append of the
-        serialized blob (write failures counted, never raised)."""
+        serialized blob (write failures counted, never raised).  Returns
+        the window's submission number (0, 1, ...), which the worker's
+        ``analysis.window`` span carries."""
         with self._cv:
             self._raise_pending()
             if self._closed:
@@ -511,9 +522,11 @@ class AsyncAnalysisSession:
                     if self._pooled:
                         # the assembler must skip this sequence
                         self._results[seq] = _DROPPED
-            self._q.append((self._submitted, snap, label))
+            seq = self._submitted
+            self._q.append((seq, snap, label))
             self._submitted += 1
             self._cv.notify_all()
+        return seq
 
     def submit_recorder(self, recorder, label: Optional[str] = None) -> None:
         """Freeze + reset the recorder's live window and enqueue it — the

@@ -46,6 +46,7 @@ from .internal import InternalReport, analyze_internal, crnm
 from .kmeans import KMeansResult
 from .regions import RegionTree
 from .roughset import DecisionTable
+from .spans import span
 from .vectors import as_matrix
 
 #: Cache stages a window can reuse from its predecessor (WindowEntry.cache_hits
@@ -171,46 +172,52 @@ def _analyze_window_cached(tree: RegionTree, measurements: Measurements,
         fp_cpu = fp_internal = fp_attrs = b""
     hits: List[str] = []
 
-    if memo is not None and fp_cpu == memo.fp_cpu:
-        ext = memo.report.external
-        hits.append("external")
-        if fp_attrs == memo.fp_attrs:
+    # one span per stage (docs/performance.md); a memo hit gives a near-empty
+    # span
+    ext_hit = memo is not None and fp_cpu == memo.fp_cpu
+    with span("analysis.external"):
+        if ext_hit:
+            ext = memo.report.external
+            hits.append("external")
+        else:
+            ext = analyze_external(tree, measurements.cpu_time,
+                                   collapse=collapse,
+                                   column_workers=column_workers)
+            if _gate_needs_exact(ext, internal_gate_s):
+                ext = analyze_external(tree, measurements.cpu_time,
+                                       collapse=COLLAPSE_EXACT,
+                                       column_workers=column_workers)
+    with span("analysis.external_root_causes"):
+        if ext_hit and fp_attrs == memo.fp_attrs:
             ext_rc = memo.report.external_root_causes
             hits.append("external_root_causes")
         else:
             ext_rc = external_root_causes(tree, attrs, ext, roles=roles,
                                           collapse=collapse)
-    else:
-        ext = analyze_external(tree, measurements.cpu_time,
-                               collapse=collapse,
-                               column_workers=column_workers)
-        if _gate_needs_exact(ext, internal_gate_s):
-            ext = analyze_external(tree, measurements.cpu_time,
-                                   collapse=COLLAPSE_EXACT,
-                                   column_workers=column_workers)
-        ext_rc = external_root_causes(tree, attrs, ext, roles=roles,
-                                      collapse=collapse)
 
     gated = (internal_gate_s is not None and not ext.exists
              and ext.severity < internal_gate_s)
-    if gated:
-        internal = _gated_internal(tree)
+    int_hit = (not gated and memo is not None
+               and fp_internal == memo.fp_internal
+               and not memo.internal_gated)
+    with span("analysis.internal"):
+        if gated:
+            internal = _gated_internal(tree)
+            hits.append("internal_gated")
+        elif int_hit:
+            internal = memo.report.internal
+            hits.append("internal")
+        else:
+            cm = crnm(measurements.wall_time, measurements.program_wall,
+                      measurements.cycles, measurements.instructions)
+            internal = analyze_internal(tree, cm)
+    with span("analysis.internal_root_causes"):
         int_rc: Optional[RootCauseReport] = None
-        hits.append("internal_gated")
-    elif (memo is not None and fp_internal == memo.fp_internal
-            and not memo.internal_gated):
-        internal = memo.report.internal
-        hits.append("internal")
-        if fp_attrs == memo.fp_attrs:
+        if int_hit and fp_attrs == memo.fp_attrs:
             int_rc = memo.report.internal_root_causes
             hits.append("internal_root_causes")
-        else:
+        elif not gated:
             int_rc = internal_root_causes(tree, attrs, internal, roles=roles)
-    else:
-        cm = crnm(measurements.wall_time, measurements.program_wall,
-                  measurements.cycles, measurements.instructions)
-        internal = analyze_internal(tree, cm)
-        int_rc = internal_root_causes(tree, attrs, internal, roles=roles)
 
     report = AnalysisReport(external=ext, internal=internal,
                             external_root_causes=ext_rc,
@@ -332,7 +339,8 @@ class WindowEntry:
         """Gap-aware :class:`repro.perfdbg.straggler.StragglerVerdict` for
         this window (a masked rank is *missing*, never a fast outlier)."""
         from repro.perfdbg.straggler import detect   # lazy: avoids cycle
-        return detect(self.report, gap_ranks=self.gap_ranks)
+        with span("analysis.straggler"):
+            return detect(self.report, gap_ranks=self.gap_ranks)
 
     def core_attributes(self, which: str = "external") -> Tuple[str, ...]:
         """The rough-set core for ``which`` ("external" or "internal") —
@@ -575,8 +583,9 @@ class AnalysisSession:
                             rank_cpu=prepared.rank_cpu,
                             cache_hits=prepared.cache_hits,
                             features=prepared.features)
-        entry = dataclasses.replace(entry,
-                                    diagnosis=self.strategy.diagnose(entry))
+        with span("analysis.diagnosis"):
+            diagnosis = self.strategy.diagnose(entry)
+        entry = dataclasses.replace(entry, diagnosis=diagnosis)
         self._last_report = prepared.report
         return self._append(entry)
 

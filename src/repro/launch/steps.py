@@ -141,11 +141,15 @@ def use_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache before the first compile
     and return its directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, wins
     (JAX reads it itself); otherwise the cache sits at the checkout's fixed
-    ``.jax_cache``: the path is part of the cache key, so it never moves."""
+    ``.jax_cache``: the path is part of the cache key, so it never moves.
+    The key keeps the programs' metadata (named scopes, source lines), which
+    profiles read: without it the cache hands back an executable compiled
+    from other code, with that code's ``op_name`` paths."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(CACHE_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

@@ -53,7 +53,6 @@ Features exercised end-to-end (CPU-sized here, mesh-parametric for pods):
 from __future__ import annotations
 
 import argparse
-import contextlib
 import dataclasses
 import os
 import statistics
@@ -233,7 +232,8 @@ def run(argv=None) -> TrainRun:
     from repro.models.model import input_specs
     from repro.optim import adamw
     from repro.perfdbg import AnalyticCosts, Instrumenter, RegionRecorder
-    from repro.perfdbg.instrument import CPU_CLOCK, NOMINAL_HZ
+    from repro.perfdbg.instrument import derived_counts, timed
+    from repro.core.spans import span
     from repro.perfdbg.attributes import device_peaks
     from repro.perfdbg.schema import SUM
     from repro.ckpt import checkpoint as ckpt
@@ -378,24 +378,15 @@ def run(argv=None) -> TrainRun:
              for nm in region_names}
     sum_fields = {f.name for f in rec.schema.fields if f.reduction == SUM}
 
-    @contextlib.contextmanager
     def region(name, *, instructions=0.0, nominal_cpi=None):
         """Instrument one region for the whole (real, simulated, or
         partitioned) pod."""
         if M == 1 and H == 1:
-            with ins.region(name, instructions=instructions,
-                            nominal_cpi=nominal_cpi):
-                yield
-            return
-        w0, c0 = time.perf_counter(), CPU_CLOCK()
-        try:
-            yield
-        finally:
-            wall, cpu = time.perf_counter() - w0, CPU_CLOCK() - c0
-            cycles = cpu * NOMINAL_HZ
-            instr = instructions
-            if nominal_cpi is not None and not instr:
-                instr = cycles / nominal_cpi
+            return ins.region(name, instructions=instructions,
+                              nominal_cpi=nominal_cpi)
+
+        def record(wall, cpu):
+            cycles, instr = derived_counts(cpu, instructions, nominal_cpi)
             if M > 1:
                 for r in range(M):
                     f = shares[r] / max(shares[0], 1e-12)
@@ -428,21 +419,16 @@ def run(argv=None) -> TrainRun:
                         wall_time=wall * f * s, cycles=cycles * f * s,
                         instructions=instr * f, **attrs)
                 host_wall[h] += wall * f * s
+        return timed(name, record)
 
-    @contextlib.contextmanager
     def program():
         if M == 1 and H == 1:
-            with ins.program():
-                yield
-            return
-        t0 = time.perf_counter()
+            return ins.program()
         if H > 1:
             host_wall[:] = 0.0
             region_wall["sum"] = 0.0
-        try:
-            yield
-        finally:
-            pw = time.perf_counter() - t0
+
+        def record(pw, _cpu):
             if M > 1:
                 for r in range(M):
                     f = shares[r] / max(shares[0], 1e-12)
@@ -454,6 +440,7 @@ def run(argv=None) -> TrainRun:
                 over = max(pw - region_wall["sum"], 0.0) / H
                 for h in range(H):
                     rec.add_program_wall(h, host_wall[h] + over)
+        return timed(None, record)
 
     engine = None
     if args.policies:
@@ -625,9 +612,20 @@ def run(argv=None) -> TrainRun:
         while time.perf_counter() < t_end:
             np.dot(np.ones(256), np.ones(256))
 
-    sync_seq = [0]   # journal sequence for the sync-analysis path
+    sync_seq = [0]   # submission number (and journal sequence), sync path
 
     def flush_window(last_step: int, win_start: int):
+        """Close the window and hand it to analysis, under one
+        ``perfdbg.flush`` span that carries the window's submission
+        number."""
+        with span("perfdbg.flush") as sp:
+            seq = submit_window(last_step, win_start)
+            if seq is not None:
+                sp.set_metadata(submission=seq)
+
+    def submit_window(last_step: int, win_start: int) -> Optional[int]:
+        """Freeze the window, gather it, and submit it (or analyze it
+        inline); returns its submission number, None if it was dropped."""
         assert rec.within_paper_budget()
         label = f"steps {win_start + 1}-{last_step + 1}"
         snap = rec.reset_window(label)
@@ -659,17 +657,18 @@ def run(argv=None) -> TrainRun:
             win_tokens.pop(label, None)
             print(f"[analysis] window w{snap.index} dropped: "
                   f"no contributors", flush=True)
-            return
+            return None
         if pipeline is not None:           # off-critical-path: enqueue only
-            pipeline.submit(snap, label=label)
-        else:
-            if journal is not None:
-                try:
-                    journal.append(sync_seq[0], snap.to_bytes(), label=label)
-                except Exception as e:
-                    print(f"[journal] append failed (contained): {e}",
-                          flush=True)
-                sync_seq[0] += 1
+            return pipeline.submit(snap, label=label)
+        seq = sync_seq[0]
+        sync_seq[0] += 1
+        if journal is not None:
+            try:
+                journal.append(seq, snap.to_bytes(), label=label)
+            except Exception as e:
+                print(f"[journal] append failed (contained): {e}",
+                      flush=True)
+        with span("analysis.window", submission=seq):
             try:
                 entry = session.ingest_snapshot(snap, label=label)
             except Exception as e:
@@ -678,10 +677,11 @@ def run(argv=None) -> TrainRun:
                 entry = session.ingest_failure(
                     label=label, error=f"{type(e).__name__}: {e}")
                 on_failure(entry)
-                return
+                return seq
             fired = engine.observe(entry, session) if engine else []
             on_window(entry)
-            apply_actions(fired)
+        apply_actions(fired)
+        return seq
 
     data.start_prefetch()
     losses = []
@@ -689,49 +689,50 @@ def run(argv=None) -> TrainRun:
     win_start = start_step
     with mesh:
         for step in range(start_step, args.steps):
-            injecting = args.inject_bottleneck_at and \
-                step + 1 >= args.inject_bottleneck_at
-            sim["slow"] = args.inject_factor \
-                if ((M > 1 or H > 1) and injecting) else 1.0
-            with program():
-                # attribute fields come from the attached cost provider
-                # (M > 1: pulled and shard-scaled by the sim's region();
-                # H > 1: scaled by each host's real slice-byte share)
-                with region("data", nominal_cpi=1.0):
-                    if injecting and M == 1 and H == 1:
-                        burn(args.inject_ms)
-                    batch = data.next_prefetched()
-                    if H > 1:
-                        # the real actuation surface: slice the global
-                        # batch by the LIVE partition; this step's
-                        # per-host attribution follows the actual bytes
-                        host_batches = data.split(batch)
-                        step_bytes[:] = [
-                            sum(int(v.nbytes) for v in hb.values())
-                            for hb in host_batches]
-                        step_shares[:] = step_bytes / step_bytes.sum()
-                    batch = jax.device_put(batch, b_sh)
-                with region("step", instructions=flops_per_step):
-                    t0 = time.perf_counter()
-                    state, metrics = compiled(state, batch)
-                    loss = float(metrics["loss"])
-                    step_s.append(time.perf_counter() - t0)
-                with region("checkpoint", nominal_cpi=1.0):
-                    if saver and (step + 1) % args.ckpt_every == 0:
-                        saver.save(step + 1, {"state": state},
-                                   extra={"data": data.state_dict()})
-            losses.append(loss)
-            if pipeline is not None:
-                # poll every step (one lock acquire): a fire lands in the
-                # shares before the *next* step, not a whole window later
-                apply_actions(pipeline.take_actions())
-            if (step + 1) % max(args.analyze_every, 1) == 0:
-                flush_window(step, win_start)
-                win_start = step + 1
-                print(f"[step {step+1}] loss={loss:.4f} "
-                      f"gnorm={float(metrics['grad_norm']):.3f}", flush=True)
-            elif (step + 1) % 5 == 0:
-                print(f"[step {step+1}] loss={loss:.4f}", flush=True)
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                injecting = args.inject_bottleneck_at and \
+                    step + 1 >= args.inject_bottleneck_at
+                sim["slow"] = args.inject_factor \
+                    if ((M > 1 or H > 1) and injecting) else 1.0
+                with program():
+                    # attribute fields come from the attached cost provider
+                    # (M > 1: pulled and shard-scaled by the sim's region();
+                    # H > 1: scaled by each host's real slice-byte share)
+                    with region("data", nominal_cpi=1.0):
+                        if injecting and M == 1 and H == 1:
+                            burn(args.inject_ms)
+                        batch = data.next_prefetched()
+                        if H > 1:
+                            # the real actuation surface: slice the global
+                            # batch by the LIVE partition; this step's
+                            # per-host attribution follows the actual bytes
+                            host_batches = data.split(batch)
+                            step_bytes[:] = [
+                                sum(int(v.nbytes) for v in hb.values())
+                                for hb in host_batches]
+                            step_shares[:] = step_bytes / step_bytes.sum()
+                        batch = jax.device_put(batch, b_sh)
+                    with region("step", instructions=flops_per_step):
+                        t0 = time.perf_counter()
+                        state, metrics = compiled(state, batch)
+                        loss = float(metrics["loss"])
+                        step_s.append(time.perf_counter() - t0)
+                    with region("checkpoint", nominal_cpi=1.0):
+                        if saver and (step + 1) % args.ckpt_every == 0:
+                            saver.save(step + 1, {"state": state},
+                                       extra={"data": data.state_dict()})
+                losses.append(loss)
+                if pipeline is not None:
+                    # poll every step (one lock acquire): a fire lands in the
+                    # shares before the *next* step, not a whole window later
+                    apply_actions(pipeline.take_actions())
+                if (step + 1) % max(args.analyze_every, 1) == 0:
+                    flush_window(step, win_start)
+                    win_start = step + 1
+                    print(f"[step {step+1}] loss={loss:.4f} "
+                          f"gnorm={float(metrics['grad_norm']):.3f}", flush=True)
+                elif (step + 1) % 5 == 0:
+                    print(f"[step {step+1}] loss={loss:.4f}", flush=True)
         if win_start < args.steps:   # trailing partial window
             flush_window(args.steps - 1, win_start)
 

@@ -89,14 +89,16 @@ def input_specs(cfg: ModelConfig, batch: int, seq: int,
 def _embed_inputs(params, cfg: ModelConfig, tokens: jax.Array,
                   patches: Optional[jax.Array] = None,
                   pos_offset: int = 0) -> jax.Array:
-    x = apply_embed(params["embed"], tokens, cfg)
-    if cfg.frontend == "vision_stub" and patches is not None:
-        pe = apply_linear(params["patch_proj"], patches.astype(x.dtype))
-        x = jnp.concatenate([pe, x[:, cfg.n_patches:]], axis=1)
-    if not cfg.use_rope:
-        S = tokens.shape[1]
-        x = x + sinusoidal(S, cfg.d_model, pos_offset).astype(x.dtype)[None]
-    return x
+    with jax.named_scope("embed"):
+        x = apply_embed(params["embed"], tokens, cfg)
+        if cfg.frontend == "vision_stub" and patches is not None:
+            pe = apply_linear(params["patch_proj"], patches.astype(x.dtype))
+            x = jnp.concatenate([pe, x[:, cfg.n_patches:]], axis=1)
+        if not cfg.use_rope:
+            S = tokens.shape[1]
+            x = x + sinusoidal(S, cfg.d_model,
+                               pos_offset).astype(x.dtype)[None]
+        return x
 
 
 def _encode(params, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
@@ -120,7 +122,11 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array,
     x = _embed_inputs(params, cfg, tokens, patches)
     enc = _encode(params, cfg, frames) if cfg.is_encdec else None
     pos = jnp.arange(tokens.shape[1])
-    x = run_stack(params["groups"], x, cfg, pos, encoder_out=enc, remat=remat)
+    # the stack's own ops (the layer loop, stacking each layer's gradients)
+    # are named by the region that holds the blocks
+    with jax.named_scope("layers"):
+        x = run_stack(params["groups"], x, cfg, pos, encoder_out=enc,
+                      remat=remat)
     return apply_norm(params["final_norm"], x, cfg.norm)
 
 
@@ -157,7 +163,8 @@ def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
     hidden = forward(params, cfg, batch["tokens"],
                      patches=batch.get("patches"),
                      frames=batch.get("frames"), remat=remat)
-    return chunked_loss(params, cfg, hidden, batch["labels"])
+    with jax.named_scope("loss"):
+        return chunked_loss(params, cfg, hidden, batch["labels"])
 
 
 # ---------------------------------------------------------------------------

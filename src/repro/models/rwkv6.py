@@ -194,7 +194,8 @@ def apply_time_mix(p, x: jax.Array, cfg, state=None, return_state: bool = False,
     logw = _decay(p, mixed["w"]).reshape(B, S, H, dh)
     s0 = state["wkv"] if state is not None else None
     fn = wkv6_chunked if (use_chunked and S > 1) else wkv6_sequential
-    y, s_final = fn(r, k, v, logw, p["u"], s0)
+    with jax.named_scope("wkv"):
+        y, s_final = fn(r, k, v, logw, p["u"], s0)
     y = _group_norm(y.reshape(B, S, d), p["ln_scale"], H)
     out = apply_linear(p["wo"], y * jax.nn.silu(g))
     if return_state:

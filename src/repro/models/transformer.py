@@ -94,61 +94,72 @@ def block_forward(kind: str, p, x: jax.Array, cfg: ModelConfig,
                   causal: bool = True,
                   collect_cache: Optional[int] = None):
     """Returns (x, cache_dict_or_None).  ``collect_cache``: target KV buffer
-    length (prefill) — None during training."""
+    length (prefill) — None during training.  The mixer and the FFN run
+    under the named scopes ``mix`` and ``ffn`` (the step's region-tree
+    names), which reach the compiled ops' ``op_name``."""
     cache: Dict[str, jax.Array] = {}
     window = cfg.window if kind.endswith("local") or kind == "local" else 0
     if _attn_kind(kind):
-        h_in = apply_norm(p["ln1"], x, cfg.norm)
-        if collect_cache is None:
-            h = attention_block(p["attn"], h_in, cfg, positions=positions,
-                                window=window, causal=causal)
-        else:
-            h, kv = _attention_with_cache(p["attn"], h_in, cfg, positions,
-                                          window, collect_cache)
-            cache.update(kv)
-        x = x + _maybe_post(p, h, cfg, "post1")
-        if "cross" in p:
-            hc = attention_block(p["cross"],
-                                 apply_norm(p["ln_cross"], x, cfg.norm), cfg,
-                                 positions=positions, encoder_out=encoder_out)
-            x = x + hc
-            if collect_cache is not None:
-                B, Se = encoder_out.shape[0], encoder_out.shape[1]
-                K, dh = cfg.n_kv_heads, cfg.d_head
-                cache["cross_k"] = apply_linear(
-                    p["cross"]["wk"], encoder_out).reshape(B, Se, K, dh)
-                cache["cross_v"] = apply_linear(
-                    p["cross"]["wv"], encoder_out).reshape(B, Se, K, dh)
-        h2_in = apply_norm(p["ln2"], x, cfg.norm)
-        if kind.startswith("moe"):
-            h2 = apply_moe(p["moe"], h2_in, cfg)
-        else:
-            h2 = apply_mlp(p["mlp"], h2_in, cfg)
-        x = x + _maybe_post(p, h2, cfg, "post2")
+        with jax.named_scope("mix"):
+            h_in = apply_norm(p["ln1"], x, cfg.norm)
+            if collect_cache is None:
+                h = attention_block(p["attn"], h_in, cfg, positions=positions,
+                                    window=window, causal=causal)
+            else:
+                h, kv = _attention_with_cache(p["attn"], h_in, cfg, positions,
+                                              window, collect_cache)
+                cache.update(kv)
+            x = x + _maybe_post(p, h, cfg, "post1")
+            if "cross" in p:
+                hc = attention_block(p["cross"],
+                                     apply_norm(p["ln_cross"], x, cfg.norm),
+                                     cfg, positions=positions,
+                                     encoder_out=encoder_out)
+                x = x + hc
+                if collect_cache is not None:
+                    B, Se = encoder_out.shape[0], encoder_out.shape[1]
+                    K, dh = cfg.n_kv_heads, cfg.d_head
+                    cache["cross_k"] = apply_linear(
+                        p["cross"]["wk"], encoder_out).reshape(B, Se, K, dh)
+                    cache["cross_v"] = apply_linear(
+                        p["cross"]["wv"], encoder_out).reshape(B, Se, K, dh)
+        with jax.named_scope("ffn"):
+            h2_in = apply_norm(p["ln2"], x, cfg.norm)
+            if kind.startswith("moe"):
+                h2 = apply_moe(p["moe"], h2_in, cfg)
+            else:
+                h2 = apply_mlp(p["mlp"], h2_in, cfg)
+            x = x + _maybe_post(p, h2, cfg, "post2")
     elif kind == "rec":
-        h_in = apply_norm(p["ln1"], x, cfg.norm)
-        if collect_cache is None:
-            h = apply_rglru(p["rec"], h_in, cfg)
-        else:
-            h, st = apply_rglru(p["rec"], h_in, cfg, return_state=True)
-            cache.update(st)
-        x = x + h
-        x = x + apply_mlp(p["mlp"], apply_norm(p["ln2"], x, cfg.norm), cfg)
+        with jax.named_scope("mix"):
+            h_in = apply_norm(p["ln1"], x, cfg.norm)
+            if collect_cache is None:
+                h = apply_rglru(p["rec"], h_in, cfg)
+            else:
+                h, st = apply_rglru(p["rec"], h_in, cfg, return_state=True)
+                cache.update(st)
+            x = x + h
+        with jax.named_scope("ffn"):
+            x = x + apply_mlp(p["mlp"], apply_norm(p["ln2"], x, cfg.norm),
+                              cfg)
     elif kind == "rwkv":
-        h_in = apply_norm(p["ln1"], x, cfg.norm)
-        if collect_cache is None:
-            h = apply_time_mix(p["tm"], h_in, cfg)
-        else:
-            h, st = apply_time_mix(p["tm"], h_in, cfg, return_state=True)
-            cache["tm_shift"], cache["wkv"] = st["shift"], st["wkv"]
-        x = x + h
-        c_in = apply_norm(p["ln2"], x, cfg.norm)
-        if collect_cache is None:
-            h2 = apply_channel_mix(p["tm"], c_in, cfg)
-        else:
-            h2, st2 = apply_channel_mix(p["tm"], c_in, cfg, return_state=True)
-            cache["cm_shift"] = st2["shift"]
-        x = x + h2
+        with jax.named_scope("mix"):
+            h_in = apply_norm(p["ln1"], x, cfg.norm)
+            if collect_cache is None:
+                h = apply_time_mix(p["tm"], h_in, cfg)
+            else:
+                h, st = apply_time_mix(p["tm"], h_in, cfg, return_state=True)
+                cache["tm_shift"], cache["wkv"] = st["shift"], st["wkv"]
+            x = x + h
+        with jax.named_scope("ffn"):
+            c_in = apply_norm(p["ln2"], x, cfg.norm)
+            if collect_cache is None:
+                h2 = apply_channel_mix(p["tm"], c_in, cfg)
+            else:
+                h2, st2 = apply_channel_mix(p["tm"], c_in, cfg,
+                                            return_state=True)
+                cache["cm_shift"] = st2["shift"]
+            x = x + h2
     return x, (cache or None)
 
 

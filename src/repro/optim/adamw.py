@@ -64,7 +64,13 @@ def global_norm(tree) -> jax.Array:
 
 def update(grads, opt_state, params, cfg: AdamWConfig
            ) -> Tuple[Any, Dict[str, Any], Dict[str, jax.Array]]:
-    """One AdamW step. Returns (new_params, new_opt_state, metrics)."""
+    """One AdamW step. Returns (new_params, new_opt_state, metrics).  Its
+    ops run under the named scope ``optimizer``."""
+    with jax.named_scope("optimizer"):
+        return _update(grads, opt_state, params, cfg)
+
+
+def _update(grads, opt_state, params, cfg: AdamWConfig):
     step = opt_state["step"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-12))

@@ -11,15 +11,20 @@ cycles:     CPU time x nominal frequency.
 instructions: supplied by the workload (analytic op counts) — PAPI has no
             TPU/CPU-portable equivalent here; DESIGN.md §8 records this
             adaptation.
+
+``timed`` is the one place a region is timed: its body runs under a
+``region.<name>`` span and its recording under a ``perfdbg.record`` span, on
+the profiler's clock (``repro.core.spans``).
 """
 from __future__ import annotations
 
 import contextlib
 import os
 import time
-from typing import Dict, Iterator, Optional
+from typing import Callable, Dict, Iterator, Optional, Tuple
 
 from repro.core import RegionTree
+from repro.core.spans import span
 from .recorder import RegionRecorder
 
 NOMINAL_HZ = 2.0e9
@@ -57,6 +62,40 @@ def CPU_CLOCK() -> float:
     return _cpu_clock()
 
 
+@contextlib.contextmanager
+def timed(name: Optional[str],
+          record: Callable[[float, float], None]) -> Iterator[None]:
+    """Time the body on the wall clock (``perf_counter``) and ``CPU_CLOCK``
+    under the span ``region.<name>`` (no span when ``name`` is None: the
+    program, whose iteration the driver's ``train`` span covers), then call
+    ``record(wall, cpu)`` under one ``perfdbg.record`` span, however many
+    ranks it records."""
+    try:
+        with span(f"region.{name}") if name is not None else \
+                contextlib.nullcontext():
+            w0 = time.perf_counter()
+            c0 = CPU_CLOCK()
+            try:
+                yield
+            finally:
+                wall = time.perf_counter() - w0
+                cpu = CPU_CLOCK() - c0
+    finally:
+        with span("perfdbg.record"):
+            record(wall, cpu)
+
+
+def derived_counts(cpu: float, instructions: float,
+                   nominal_cpi: Optional[float]) -> Tuple[float, float]:
+    """(cycles, instructions) of a region from its CPU time: cycles at the
+    nominal frequency; with ``nominal_cpi`` and no analytic count, the
+    instructions those cycles retire at that CPI."""
+    cycles = cpu * NOMINAL_HZ
+    if nominal_cpi is not None and not instructions:
+        instructions = cycles / nominal_cpi
+    return cycles, instructions
+
+
 class Instrumenter:
     """Times named regions for one rank and feeds a RegionRecorder."""
 
@@ -71,10 +110,9 @@ class Instrumenter:
     def region_id(self, name: str) -> int:
         return self._names[name]
 
-    @contextlib.contextmanager
     def region(self, name: str, *, instructions: float = 0.0,
                nominal_cpi: Optional[float] = None,
-               **attrs: Optional[float]) -> Iterator[None]:
+               **attrs: Optional[float]):
         """Time a region.  Keyword attributes are forwarded to the recorder
         and must belong to its schema (e.g. ``disk_io=...`` under the
         ``paper`` schema, ``collective_bytes=...`` under ``tpu``).  When
@@ -89,28 +127,18 @@ class Instrumenter:
         cycles at that CPI, keeping the region's CRNM proportional to its
         time share rather than exploding on a token-count denominator."""
         rid = self._names[name]
-        w0 = time.perf_counter()
-        c0 = CPU_CLOCK()
-        try:
-            yield
-        finally:
-            wall = time.perf_counter() - w0
-            cpu = CPU_CLOCK() - c0
-            cycles = cpu * NOMINAL_HZ
-            if nominal_cpi is not None and not instructions:
-                instructions = cycles / nominal_cpi
+
+        def record(wall: float, cpu: float) -> None:
+            cycles, instr = derived_counts(cpu, instructions, nominal_cpi)
             self.recorder.add(
                 self.rank, rid, cpu_time=cpu, wall_time=wall,
-                cycles=cycles, instructions=instructions, **attrs)
+                cycles=cycles, instructions=instr, **attrs)
+        return timed(name, record)
 
-    @contextlib.contextmanager
-    def program(self) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.recorder.add_program_wall(self.rank,
-                                           time.perf_counter() - t0)
+    def program(self):
+        """Time one whole step of this rank (the paper's program wall)."""
+        return timed(None, lambda wall, _cpu: self.recorder.add_program_wall(
+            self.rank, wall))
 
 
 def build_step_tree(layer_names, granularity: str = "layer") -> RegionTree:

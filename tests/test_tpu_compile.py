@@ -3,8 +3,10 @@ real widths and the smoke configuration's full train step (rwkv6-3b at its
 published width, 4 layers, batch 4 x seq 1024, as ``chip_smoke.py`` runs
 it).  These catch what interpret mode cannot: tiling and alignment faults,
 VMEM limits, and a step that does not fit the chip's memory."""
+import contextlib
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -84,8 +86,13 @@ def _smoke_step(devices):
         return cfg, jitted.lower(st_shapes, bshapes).compile()
 
 
-def test_smoke_train_step_fits_one_chip(topo):
-    cfg, compiled = _smoke_step(topo.devices[:1])
+@pytest.fixture(scope="module")
+def smoke_one_chip(topo):
+    return _smoke_step(topo.devices[:1])
+
+
+def test_smoke_train_step_fits_one_chip(smoke_one_chip, topo):
+    cfg, compiled = smoke_one_chip
     ma = compiled.memory_analysis()
     # donated state aliases its outputs: arguments plus temporaries is the
     # step's footprint on the device
@@ -106,3 +113,29 @@ def test_smoke_train_step_fsdp_four_chips(topo):
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 4e9
     assert "all-gather" in compiled.as_text()
+
+
+def _program(hlo_text):
+    """The module's computations with the metadata left out (op_name,
+    source lines and the stack-frame tables) and instructions renumbered
+    in order of appearance."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", hlo_text)
+    lines = [ln for ln in text.splitlines()
+             if ln.startswith((" ", "%", "ENTRY", "ROOT", "}", "HloModule"))]
+    names = {}
+    return [re.sub(r"%[\w.\-]+",
+                   lambda m: names.setdefault(m.group(0), f"%{len(names)}"),
+                   ln) for ln in lines]
+
+
+def test_named_scopes_change_only_the_step_metadata(smoke_one_chip, topo,
+                                                    monkeypatch):
+    """The scopes reach the chip's compiled step as op_name metadata and
+    change nothing else in it."""
+    _, scoped = smoke_one_chip
+    assert "/mix/wkv/" in scoped.as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    _, plain = _smoke_step(topo.devices[:1])
+    assert "/mix/wkv/" not in plain.as_text()
+    assert _program(scoped.as_text()) == _program(plain.as_text())
