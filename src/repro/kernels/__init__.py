@@ -3,4 +3,4 @@ flash attention, RG-LRU scan, RWKV6 WKV.  See ops.py for public wrappers."""
 from . import ops, ref
 from .flash_attention import flash_attention
 from .rglru_scan import rglru_scan_kernel
-from .wkv6 import wkv6_kernel
+from .wkv6 import wkv6_bwd, wkv6_fwd

@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (the ground truth for tests)."""
+"""Pure-jnp oracles for the Pallas kernels (the ground truth for tests); the
+WKV kernels' is ``models.rwkv6.wkv6_sequential``."""
 from __future__ import annotations
 
 from typing import Optional
@@ -46,20 +47,3 @@ def rglru_scan_ref(a: jax.Array, b: jax.Array,
     _, hs = jax.lax.scan(step, h, (jnp.moveaxis(a, 1, 0), jnp.moveaxis(b, 1, 0)))
     return jnp.moveaxis(hs, 0, 1)
 
-
-def wkv6_ref(r, k, v, logw, u, s0=None):
-    """Sequential WKV6 over merged (BH, T, dh) tensors; u: (BH, dh)."""
-    BH, T, dh = r.shape
-    f32 = jnp.float32
-    s = jnp.zeros((BH, dh, dh), f32) if s0 is None else s0.astype(f32)
-
-    def step(s, inp):
-        r_t, k_t, v_t, lw_t = [a.astype(f32) for a in inp]
-        kv = jnp.einsum("bd,be->bde", k_t, v_t)
-        y = jnp.einsum("bd,bde->be", r_t, s + u.astype(f32)[:, :, None] * kv)
-        s_new = jnp.exp(lw_t)[..., None] * s + kv
-        return s_new, y
-
-    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (r, k, v, logw))
-    s_final, ys = jax.lax.scan(step, s, xs)
-    return jnp.moveaxis(ys, 0, 1).astype(r.dtype), s_final

@@ -7,11 +7,13 @@ with w_t = exp(-exp(w0 + lora_w(x~_t)))  (data-dependent decay),
 token-shift data-dependent lerps for r/k/v/w/g, per-head groupnorm on y,
 and a squared-ReLU channel-mix FFN.
 
-The sequence form here is *chunkwise parallel* (matmul-heavy for the MXU):
+The sequence form is *chunkwise parallel* (matmul-heavy for the MXU):
 within a chunk the contribution is a masked (q~ k~^T) v matmul in log-decay
-space; across chunks the (dh x dh) state propagates with a sequential scan.
-``repro.kernels.wkv6`` is the Pallas TPU kernel; this module is the jnp
-fallback and the oracle for kernel tests.
+space; across chunks the (dh x dh) state propagates sequentially.  On a TPU,
+where the shapes tile, it runs as the Pallas kernels of
+``repro.kernels.wkv6`` (forward and backward); elsewhere as
+``wkv6_chunked``, a ``lax.scan`` over chunks.  ``wkv6_sequential``, the
+token-by-token recurrence, is the oracle of both and the decode path.
 """
 from __future__ import annotations
 
@@ -21,6 +23,9 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro.kernels import ops, wkv6
+from repro.runtime import batch_map
 
 from .layers import ParamSpec, linear_spec, apply_linear
 
@@ -165,6 +170,12 @@ def wkv6_sequential(r, k, v, logw, u, state=None):
     return jnp.moveaxis(ys, 0, 1).astype(r.dtype), s_final
 
 
+def wkv6_kernel_fits(S: int, H: int, dh: int) -> bool:
+    """Whether the sequence form runs as the Pallas kernels: on a TPU, with
+    shapes that tile them."""
+    return jax.default_backend() == "tpu" and wkv6.fits(S, H, dh)
+
+
 def _group_norm(x: jax.Array, scale: jax.Array, H: int, eps: float = 64e-5) -> jax.Array:
     """Per-head groupnorm on (B, T, d) with d = H * dh (RWKV6 ln_x)."""
     B, T, d = x.shape
@@ -193,9 +204,15 @@ def apply_time_mix(p, x: jax.Array, cfg, state=None, return_state: bool = False,
     g = apply_linear(p["wg"], mixed["g"])
     logw = _decay(p, mixed["w"]).reshape(B, S, H, dh)
     s0 = state["wkv"] if state is not None else None
-    fn = wkv6_chunked if (use_chunked and S > 1) else wkv6_sequential
     with jax.named_scope("wkv"):
-        y, s_final = fn(r, k, v, logw, p["u"], s0)
+        if use_chunked and wkv6_kernel_fits(S, H, dh):
+            if s0 is None:
+                s0 = jnp.zeros((B, H, dh, dh), jnp.float32)
+            y, s_final = batch_map(ops.wkv6, r, k, v, logw, p["u"], s0,
+                                   replicated=(4,))
+        else:
+            fn = wkv6_chunked if (use_chunked and S > 1) else wkv6_sequential
+            y, s_final = fn(r, k, v, logw, p["u"], s0)
     y = _group_norm(y.reshape(B, S, d), p["ln_scale"], H)
     out = apply_linear(p["wo"], y * jax.nn.silu(g))
     if return_state:

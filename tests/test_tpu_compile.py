@@ -62,11 +62,29 @@ def test_flash_attention_dh128_seq1024(one_chip):
     _compile(lambda q, k, v: ops.attention(q, k, v), q, q, q)
 
 
-def test_wkv6_rwkv6_3b_heads_t1024(one_chip):
-    x = jax.ShapeDtypeStruct((1, 1024, 40, 64), jnp.bfloat16,
+def _wkv6_shapes(one_chip):
+    """rwkv6-3b's heads at the smoke step's batch and sequence, as the
+    time-mix hands them over: r, k, v in bf16, the log-decay in f32."""
+    x = jax.ShapeDtypeStruct((SMOKE_BATCH, SMOKE_SEQ, 40, 64), jnp.bfloat16,
                              sharding=one_chip)
+    w = jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=one_chip)
     u = jax.ShapeDtypeStruct((40, 64), jnp.float32, sharding=one_chip)
-    _compile(lambda r, k, v, w, u: ops.wkv6(r, k, v, w, u), x, x, x, x, u)
+    return x, x, x, w, u
+
+
+def test_wkv6_rwkv6_3b_heads_t1024(one_chip):
+    compiled = _compile(lambda r, k, v, w, u: ops.wkv6(r, k, v, w, u),
+                        *_wkv6_shapes(one_chip))
+    assert "wkv6_fwd" in compiled.as_text()
+
+
+def test_wkv6_backward_rwkv6_3b_heads_t1024(one_chip):
+    def loss(r, k, v, w, u):
+        y, s = ops.wkv6(r, k, v, w, u)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(s)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                        *_wkv6_shapes(one_chip))
+    assert "wkv6_bwd" in compiled.as_text()
 
 
 def test_rglru_scan_width_4096(one_chip):
@@ -80,7 +98,9 @@ def _smoke_step(devices):
     mesh = Mesh(np.asarray(devices).reshape(len(devices), 1),
                 ("data", "model"))
     bshapes = input_specs(cfg, SMOKE_BATCH, SMOKE_SEQ, "train")
-    with mesh:
+    # the model asks the backend, which is this CPU, to choose its TPU path
+    with mesh, pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
         jitted, (st_shapes, _, _) = steps_lib.jit_train_step(
             cfg, opt, mesh, bshapes)
         return cfg, jitted.lower(st_shapes, bshapes).compile()
@@ -105,6 +125,22 @@ def test_smoke_train_step_fits_one_chip(smoke_one_chip, topo):
     assert 0.9 < flops / model < 1.5
 
 
+def test_smoke_train_step_runs_the_wkv_kernels(smoke_one_chip):
+    """The WKV kernels sit under the time-mix's scope in the forward, in
+    the layer's recomputed forward and in the transposed computation."""
+    _, compiled = smoke_one_chip
+    calls = {m.group(1): m.group(2) for m in re.finditer(
+        r"%(wkv6_(?:fwd|bwd)\.\d+) = .*custom_call_target=\"tpu_custom_call"
+        r'.*op_name="([^"]*)"', compiled.as_text())}
+    paths = sorted(calls.values())
+    assert all("/mix/wkv/" in p for p in paths), paths
+    assert any("wkv6_fwd" in p and "/jvp(layers)/" in p for p in paths)
+    assert any("wkv6_fwd" in p and "transpose(jvp(layers))" in p
+               for p in paths)
+    assert any("wkv6_bwd" in p and "transpose(jvp(layers))" in p
+               for p in paths)
+
+
 def test_smoke_train_step_fsdp_four_chips(topo):
     """FSDP over (4, 1) at one row per chip: the TPU compiler refuses this
     step unless ``jit_train_step`` keeps async collective fusion out of
@@ -113,6 +149,9 @@ def test_smoke_train_step_fsdp_four_chips(topo):
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 4e9
     assert "all-gather" in compiled.as_text()
+    # per chip, on its own rows of the batch
+    assert re.search(r"wkv6_bwd\.\d+ = \(bf16\[1,1024,2560\]",
+                     compiled.as_text())
 
 
 def _program(hlo_text):
