@@ -315,6 +315,8 @@ def run(argv=None) -> TrainRun:
         print("[costs] coverage: " + coverage, flush=True)
     step_costs = provider.region_costs("step")
     flops_per_step = step_costs.get("hlo_flops", 0.0)
+    # one chip's collective traffic a step, an argument of each step's span
+    step_collective_bytes = int(step_costs.get("collective_bytes", 0.0))
     print(f"[costs] {costs_mode} step: "
           f"hlo_flops={step_costs.get('hlo_flops', 0.0):.3e} "
           f"hbm_bytes={step_costs.get('hbm_bytes', 0.0):.3e} "
@@ -689,7 +691,9 @@ def run(argv=None) -> TrainRun:
     win_start = start_step
     with mesh:
         for step in range(start_step, args.steps):
-            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            with jax.profiler.StepTraceAnnotation(
+                    "train", step_num=step,
+                    collective_bytes=step_collective_bytes):
                 injecting = args.inject_bottleneck_at and \
                     step + 1 >= args.inject_bottleneck_at
                 sim["slow"] = args.inject_factor \

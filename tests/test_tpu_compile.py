@@ -1,8 +1,10 @@
 """Compiles for a described TPU v5e, no chip attached: the Pallas kernels at
-real widths and the smoke configuration's full train step (rwkv6-3b at its
+real widths, the smoke configuration's full train step (rwkv6-3b at its
 published width, 4 layers, batch 4 x seq 1024, as ``chip_smoke.py`` runs
-it).  These catch what interpret mode cannot: tiling and alignment faults,
-VMEM limits, and a step that does not fit the chip's memory."""
+it), and the whole model's step, 32 layers FSDP over four chips, as the
+benchmark's ``rwkv6-3b-fsdp4.clean`` cell runs it.  These catch what
+interpret mode cannot: tiling and alignment faults, VMEM limits, and a step
+that does not fit the chip's memory."""
 import contextlib
 import dataclasses
 import os
@@ -92,8 +94,8 @@ def test_rglru_scan_width_4096(one_chip):
     _compile(lambda a, b: ops.rglru_scan(a, b), a, a)
 
 
-def _smoke_step(devices):
-    cfg = dataclasses.replace(get_config("rwkv6-3b"), n_layers=SMOKE_LAYERS)
+def _smoke_step(devices, layers=SMOKE_LAYERS):
+    cfg = dataclasses.replace(get_config("rwkv6-3b"), n_layers=layers)
     opt = adamw.AdamWConfig(lr=3e-4, warmup_steps=5, decay_steps=10)
     mesh = Mesh(np.asarray(devices).reshape(len(devices), 1),
                 ("data", "model"))
@@ -152,6 +154,40 @@ def test_smoke_train_step_fsdp_four_chips(topo):
     # per chip, on its own rows of the batch
     assert re.search(r"wkv6_bwd\.\d+ = \(bf16\[1,1024,2560\]",
                      compiled.as_text())
+
+
+# one v5e chip's memory_stats()["bytes_limit"] (a chip run), and the room
+# the whole model's step leaves under it for the rest of the process: the
+# benchmark's probes of the state, the next batch, the runtime
+V5E_BYTES_LIMIT = 16_909_334_528
+HEADROOM = 1e9
+
+
+@pytest.fixture(scope="module")
+def whole_four_chips(topo):
+    """rwkv6-3b at its published 32 layers, FSDP over four chips, as the
+    ``rwkv6-3b-fsdp4.clean`` cell runs it."""
+    return _smoke_step(topo.devices[:4],
+                       layers=get_config("rwkv6-3b").n_layers)
+
+
+def test_whole_model_fsdp_four_chips_fits_with_headroom(whole_four_chips):
+    cfg, compiled = whole_four_chips
+    assert cfg.n_layers == 32
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used < V5E_BYTES_LIMIT - HEADROOM, used
+
+
+def test_whole_model_fsdp_four_chips_shards_the_work(whole_four_chips):
+    """Each chip runs the WKV kernels on its own row of the batch, and the
+    cost provider sees the step's collectives."""
+    _, compiled = whole_four_chips
+    text = compiled.as_text()
+    for kernel in ("wkv6_fwd", "wkv6_bwd"):
+        shapes = re.findall(rf"%{kernel}\.\d+ = \((\w+\[[\d,]*\])", text)
+        assert shapes and set(shapes) == {"bf16[1,1024,2560]"}, shapes
+    assert Analyzer(text).stats().total_collective_bytes > 0
 
 
 def _program(hlo_text):

@@ -182,3 +182,39 @@ def test_a_cached_step_keeps_its_own_scopes(tmp_path):
             for kind in ("scoped", "plain", "scoped")]
     assert any(tmp_path.iterdir())
     assert said == [["True"], ["False"], ["True"]]
+
+
+def test_the_step_span_carries_the_compiled_collective_bytes():
+    """On a two-device FSDP mesh every ``train`` span carries one chip's
+    collective bytes of the compiled step, as the ``[costs]`` line prints
+    them.  Subprocess: the device count is set before jax starts."""
+    import json
+    import os
+    import subprocess
+    import sys
+    code = ("import json, jax\n"
+            "seen = []\n"
+            "class Step:\n"
+            "    def __init__(self, name, **meta): seen.append(meta)\n"
+            "    def __enter__(self): return self\n"
+            "    def __exit__(self, *exc): return None\n"
+            "jax.profiler.StepTraceAnnotation = Step\n"
+            "from repro.launch.train import run\n"
+            "run(sys_argv)\n"
+            "print('SPANS ' + json.dumps(seen))\n")
+    assert DRIVER[-2:] == ["--costs", "analytic"]
+    argv = DRIVER[:-1] + ["hlo", "--devices", "2"]
+    env = dict(os.environ, PYTHONPATH="src",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    out = subprocess.run(
+        [sys.executable, "-c", f"sys_argv = {argv!r}\n" + code], env=env,
+        capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    costs = re.search(r"^\[costs\] hlo step:.* collective_bytes=(\S+)",
+                      out.stdout, re.M)
+    spans = json.loads(out.stdout.split("SPANS ", 1)[1].splitlines()[0])
+    assert [s["step_num"] for s in spans] == list(range(STEPS))
+    got = {s["collective_bytes"] for s in spans}
+    assert len(got) == 1 and got.pop() > 0
+    assert spans[0]["collective_bytes"] == pytest.approx(
+        float(costs.group(1)), rel=1e-3)
