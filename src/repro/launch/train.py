@@ -230,6 +230,7 @@ def run(argv=None) -> TrainRun:
     from repro.launch.mesh import make_host_mesh
     from repro.launch import steps as steps_lib
     from repro.models.model import input_specs
+    from repro.models.transformer import remat_label
     from repro.optim import adamw
     from repro.perfdbg import AnalyticCosts, Instrumenter, RegionRecorder
     from repro.perfdbg.instrument import derived_counts, timed
@@ -317,12 +318,15 @@ def run(argv=None) -> TrainRun:
     flops_per_step = step_costs.get("hlo_flops", 0.0)
     # one chip's collective traffic a step, an argument of each step's span
     step_collective_bytes = int(step_costs.get("collective_bytes", 0.0))
+    # the layer stack's remat split the step was compiled with, beside it
+    remat = remat_label(st_shapes["params"]["groups"], cfg, args.batch,
+                        args.seq)
     print(f"[costs] {costs_mode} step: "
           f"hlo_flops={step_costs.get('hlo_flops', 0.0):.3e} "
           f"hbm_bytes={step_costs.get('hbm_bytes', 0.0):.3e} "
           f"collective_bytes={step_costs.get('collective_bytes', 0.0):.3e} "
-          f"hbm_boundedness={step_costs.get('hbm_boundedness', 0.0):.3f}",
-          flush=True)
+          f"hbm_boundedness={step_costs.get('hbm_boundedness', 0.0):.3f} "
+          f"remat={remat}", flush=True)
 
     # region tree for the instrumented step.  Three rank layouts:
     #   M = H = 1: the real single shard of this container.
@@ -693,7 +697,7 @@ def run(argv=None) -> TrainRun:
         for step in range(start_step, args.steps):
             with jax.profiler.StepTraceAnnotation(
                     "train", step_num=step,
-                    collective_bytes=step_collective_bytes):
+                    collective_bytes=step_collective_bytes, remat=remat):
                 injecting = args.inject_bottleneck_at and \
                     step + 1 >= args.inject_bottleneck_at
                 sim["slow"] = args.inject_factor \

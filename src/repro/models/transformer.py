@@ -311,16 +311,65 @@ def _split_factor(n: int) -> int:
     return best
 
 
+def remat_split(n: int, carry_bytes: int, weight_bytes: int
+                ) -> Tuple[int, int]:
+    """(n_outer, n_inner) of a scanned group of ``n`` layers under remat:
+    ``(n, 1)`` scans once, each layer checkpointed; ``n_inner > 1`` is the
+    two-level split, ``n_outer`` checkpointed super-layers of ``n_inner``
+    layers each, and ``n - n_outer * n_inner`` tail layers scanned once.
+
+    Two-level stashes ``n_outer + n_inner`` residual-stream carries for the
+    backward instead of ``n``, but recomputes each super-layer's inner
+    forward once more: one full extra forward of the group, and under FSDP
+    one more gather of its weights.  It pays only where the carries are a
+    large share of the step's memory, so it is taken where the ``n`` carries
+    (``carry_bytes`` each) outweigh one compute-dtype copy of the group's
+    weights (``weight_bytes``), the bytes the extra forward brings in again.
+    Both sides shrink alike under FSDP and data parallelism (weights split
+    over ``data``, carries over ``batch``), so global shapes decide it.
+    Groups under 9 layers always scan once."""
+    if n < 9 or n * carry_bytes <= weight_bytes:
+        return n, 1
+    n_inner = _split_factor(n)
+    if n_inner == 1:
+        # prime depth (e.g. gemma2's 23 units): split off a tail so the
+        # main run still gets the sqrt-remat treatment
+        n_inner = _split_factor(n - 1)
+    return n // n_inner, n_inner
+
+
+def _group_weight_bytes(gp, cfg: ModelConfig) -> int:
+    """One compute-dtype copy of a group's weights, in bytes."""
+    return (sum(a.size for a in jax.tree_util.tree_leaves(gp))
+            * jnp.dtype(cfg.compute_dtype).itemsize)
+
+
+def remat_label(params_groups, cfg: ModelConfig, batch: int, seq: int) -> str:
+    """The split ``run_stack`` compiles for each group, as ``n_outer x
+    n_inner`` (``+tail`` where a tail is left), groups joined by ``,``: for
+    example ``32x1`` or ``8x4``.  Takes the parameters or their shapes."""
+    carry = batch * seq * cfg.d_model * jnp.dtype(cfg.compute_dtype).itemsize
+    out = []
+    for gp, (_, n) in zip(params_groups, group_meta(cfg)):
+        o, i = remat_split(n, carry, _group_weight_bytes(gp, cfg))
+        tail = n - o * i
+        out.append(f"{o}x{i}" + (f"+{tail}" if tail else ""))
+    return ",".join(out)
+
+
 def run_stack(params_groups, x: jax.Array, cfg: ModelConfig,
               positions: jax.Array, encoder_out: Optional[jax.Array] = None,
               causal: bool = True, remat: bool = True) -> jax.Array:
     """Training/prefill-without-cache forward through all groups.
 
-    Deep groups use two-level scan remat: an outer checkpointed scan over
-    n_outer super-iterations, each an inner scan of n_inner layers.  The
-    backward then stashes n_outer + n_inner residual-stream carries instead
-    of n (sqrt(N) activation memory — the classic recursive-checkpoint
-    trade; e.g. qwen-110B: 80 carries -> 18)."""
+    Under remat each layer is checkpointed, and ``remat_split`` chooses per
+    group between one scan and a two-level scan: an outer checkpointed scan
+    over n_outer super-iterations, each an inner scan of n_inner layers.
+    Two-level stashes n_outer + n_inner residual-stream carries instead of
+    n (sqrt(N) activation memory, the classic recursive-checkpoint trade;
+    e.g. qwen-110B at train_4k: 80 carries -> 18) at the price of a third
+    forward, so it is taken only where the carries outweigh the group's
+    weights; rwkv6-3b at 4 x 1024 scans its 32 layers once."""
     for g, (unit, n) in enumerate(group_meta(cfg)):
         gp = params_groups[g]
 
@@ -332,16 +381,13 @@ def run_stack(params_groups, x: jax.Array, cfg: ModelConfig,
                 h = constrain(h, "batch")
             return h, None
 
+        n_outer, n_inner = 1, 1
         if remat:
             body = jax.checkpoint(body, policy=REMAT_POLICY)
-        n_inner = _split_factor(n) if (remat and n >= 9) else 1
-        if n_inner == 1 and remat and n >= 9:
-            # prime depth (e.g. gemma2's 23 units): split off a tail so the
-            # main run still gets the sqrt-remat treatment
-            n_inner = _split_factor(n - 1) or 1
+            n_outer, n_inner = remat_split(
+                n, x.size * x.dtype.itemsize, _group_weight_bytes(gp, cfg))
         if n_inner > 1:
-            n_main = (n // n_inner) * n_inner
-            n_outer = n_main // n_inner
+            n_main = n_outer * n_inner
 
             def slice_main(a):
                 return a[:n_main].reshape(n_outer, n_inner, *a.shape[1:])

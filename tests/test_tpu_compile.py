@@ -143,6 +143,20 @@ def test_smoke_train_step_runs_the_wkv_kernels(smoke_one_chip):
                for p in paths)
 
 
+def _kernel_calls(compiled, kernel):
+    """How many custom calls of ``kernel`` the compiled step makes."""
+    return len(re.findall(rf"^\s*%{kernel}\.\d+ = ", compiled.as_text(),
+                          re.M))
+
+
+def test_smoke_train_step_recomputes_the_forward_once(smoke_one_chip):
+    """Four layers scan once: the forward, its one recomputation in the
+    backward, and the backward kernel."""
+    _, compiled = smoke_one_chip
+    assert _kernel_calls(compiled, "wkv6_fwd") == 2
+    assert _kernel_calls(compiled, "wkv6_bwd") == 1
+
+
 def test_smoke_train_step_fsdp_four_chips(topo):
     """FSDP over (4, 1) at one row per chip: the TPU compiler refuses this
     step unless ``jit_train_step`` keeps async collective fusion out of
@@ -188,6 +202,16 @@ def test_whole_model_fsdp_four_chips_shards_the_work(whole_four_chips):
         shapes = re.findall(rf"%{kernel}\.\d+ = \((\w+\[[\d,]*\])", text)
         assert shapes and set(shapes) == {"bf16[1,1024,2560]"}, shapes
     assert Analyzer(text).stats().total_collective_bytes > 0
+
+
+def test_whole_model_fsdp_four_chips_recomputes_the_forward_once(
+        whole_four_chips):
+    """The 32 layers' carries (0.67 GB at 4 x 1024) weigh less than their
+    weights in bf16 (5.53 GB), so the stack scans once: no two-level split,
+    whose super-layers would run each layer's forward a third time."""
+    _, compiled = whole_four_chips
+    assert _kernel_calls(compiled, "wkv6_fwd") == 2
+    assert _kernel_calls(compiled, "wkv6_bwd") == 1
 
 
 def _program(hlo_text):

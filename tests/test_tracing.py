@@ -186,8 +186,9 @@ def test_a_cached_step_keeps_its_own_scopes(tmp_path):
 
 def test_the_step_span_carries_the_compiled_collective_bytes():
     """On a two-device FSDP mesh every ``train`` span carries one chip's
-    collective bytes of the compiled step, as the ``[costs]`` line prints
-    them.  Subprocess: the device count is set before jax starts."""
+    collective bytes of the compiled step and the layer stack's remat split,
+    as the ``[costs]`` line prints them.  Subprocess: the device count is
+    set before jax starts."""
     import json
     import os
     import subprocess
@@ -218,3 +219,7 @@ def test_the_step_span_carries_the_compiled_collective_bytes():
     assert len(got) == 1 and got.pop() > 0
     assert spans[0]["collective_bytes"] == pytest.approx(
         float(costs.group(1)), rel=1e-3)
+    remat = re.search(r"^\[costs\] hlo step:.* remat=(\S+)$", out.stdout,
+                      re.M)
+    assert remat.group(1) == "2x1"
+    assert {s["remat"] for s in spans} == {"2x1"}
